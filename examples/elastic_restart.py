@@ -17,7 +17,7 @@ from repro.configs import get_config
 from repro.core import sasg_config
 from repro.data import token_stream
 from repro.dist.strategy import Strategy, choose_strategy
-from repro.launch.mesh import make_test_mesh
+from repro.compat import make_mesh
 from repro.models import build
 from repro.optim import constant
 from repro.train import Trainer, TrainerConfig, build_train_step
@@ -37,7 +37,7 @@ def main():
 
     # phase 1: flat 4-worker mesh; a fault fires at step 7 and the Trainer
     # recovers from the last checkpoint automatically
-    mesh1 = make_test_mesh((4, 2), ("data", "model"))
+    mesh1 = make_mesh((4, 2), ("data", "model"))
     strat1 = Strategy("flat", ("data",), ("data",), None, None, "model", 4)
     built1 = build_train_step(model, scfg, mesh1, strat1, constant(0.05))
     boom = {7}
@@ -56,7 +56,7 @@ def main():
 
     # phase 2: resume the checkpoint on a 2-pod hierarchical mesh (elastic
     # resize: 4 flat workers -> 2 pod workers)
-    mesh2 = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh2 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     strat2 = choose_strategy(mesh2, sasg_enabled=True)
     built2 = build_train_step(model, scfg, mesh2, strat2, constant(0.05))
     tr2 = Trainer(built2, data(),

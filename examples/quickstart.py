@@ -14,7 +14,7 @@ from repro.configs import get_config
 from repro.core import sasg_config
 from repro.data import token_stream
 from repro.dist.strategy import choose_strategy
-from repro.launch.mesh import make_test_mesh
+from repro.compat import make_mesh
 from repro.models import build
 from repro.optim import constant
 from repro.train import build_train_step
@@ -24,7 +24,7 @@ def main():
     cfg = get_config("llama3_8b").reduced()
     model = build(cfg)
 
-    mesh = make_test_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     strategy = choose_strategy(mesh, sasg_enabled=True)
     print(f"strategy: {strategy.name} ({strategy.num_workers} SASG workers, "
           f"TP over '{strategy.tp_axis}')")

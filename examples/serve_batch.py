@@ -12,7 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
-from repro.launch.mesh import make_test_mesh
+from repro.compat import make_mesh
 from repro.models import build
 from repro.serve import BatchedServer, Request, build_serve
 
@@ -20,7 +20,7 @@ from repro.serve import BatchedServer, Request, build_serve
 def main():
     cfg = get_config("internvl2_2b").reduced()
     model = build(cfg)
-    mesh = make_test_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     serve = build_serve(model, mesh, fsdp="data", tp="model")
     params = jax.jit(model.init, out_shardings=serve.param_shardings)(
         jax.random.PRNGKey(0)

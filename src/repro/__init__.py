@@ -1,5 +1,3 @@
 """repro: SASG (sparse + adaptive stochastic gradient) distributed-training
 framework in JAX. See DESIGN.md for the system inventory."""
-from . import compat as _compat  # noqa: F401  (installs JAX version shims)
-
 __version__ = "0.1.0"

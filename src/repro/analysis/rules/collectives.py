@@ -12,7 +12,7 @@ Exempt:
 - metadata queries (``axis_index``/``axis_size``) — no payload;
 - collectives whose operand is a numeric literal (``psum(1, axis)`` is the
   idiomatic static axis-size query);
-- ``repro/comm/`` itself and ``repro/compat.py`` (shim for the above).
+- ``repro/comm/`` itself.
 
 Known-accepted sites (the GPipe activation ring in ``dist/pipeline.py`` —
 activation traffic by construction, classified and itemized by the HLO
@@ -34,7 +34,7 @@ from ._common import (
     is_numeric_literal,
 )
 
-EXEMPT_PATHS = ("repro/comm/", "repro/compat.py", "repro/analysis/")
+EXEMPT_PATHS = ("repro/comm/", "repro/analysis/")
 
 
 class _Visitor(ScopedVisitor):

@@ -285,10 +285,17 @@ class Transport:
         return self.compressor.init(self._lay_out(params))
 
     def zero_payload(self, params: Tree) -> Tree:
-        """Payload-shaped zeros: compress a zero tree (values come out 0)."""
+        """Payload-shaped zeros: the structure and dtypes of compressing a
+        zero tree, every value and index 0. Only the shapes are traced, so
+        no compressor (and no Pallas kernel) runs here: the state init is
+        auto-partitioned over the whole mesh, where a Mosaic kernel cannot
+        be."""
         zeros = tree_zeros_like(params, dtype=jnp.float32)
-        payload, _ = self.encode(self.init_state(zeros), zeros, jax.random.PRNGKey(0))
-        return payload
+        shapes = jax.eval_shape(
+            lambda z: self.encode(self.init_state(z), z, jax.random.PRNGKey(0))[0],
+            zeros,
+        )
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
     def encode(self, state: Tree, g: Tree, key) -> tuple:
         """Lay out the (full-shape) quantity tree and compress it.

@@ -1,112 +1,19 @@
-"""JAX version-compatibility layer.
+"""Mesh construction for the installed JAX (0.9).
 
-The framework targets the modern shard_map surface (``jax.shard_map`` with
-``axis_names=`` / ``check_vma=`` and ``jax.sharding.AxisType`` meshes) but
-must also run on older 0.4.x installs where that surface does not exist and
-where the bundled XLA SPMD partitioner CHECK-fails on collectives
-(all-gather / ppermute) placed inside *partial-auto* shard_map regions —
-the exact failure family pinned in ``tests/test_known_limits.py``.
-
-Importing this module (``repro/__init__.py`` does it for every consumer)
-installs three shims, each only when the running JAX lacks the native API:
-
-- ``jax.shard_map``: forwards to ``jax.experimental.shard_map.shard_map``,
-  translating ``axis_names`` (manual axes) into the legacy ``auto``
-  complement and ``check_vma`` into ``check_rep``. On partitioner-broken
-  jaxlibs the auto axes are *degraded to manual*: specs are unchanged, so
-  tensors simply stay replicated (instead of TP-sharded) over the former
-  auto axes inside the region. Identical numerics, more per-device memory —
-  acceptable on the CPU test meshes; real accelerator jobs run new JAX.
-- ``jax.lax.axis_size``: the classic ``psum(1, axis)`` idiom (returns a
-  static int for a concrete operand).
-- ``make_mesh(shape, axes, axis_types=None)`` helper: builds a mesh with
-  ``axis_types`` where supported and silently without it where not, so
-  launch/test code has one spelling for both JAX generations.
+``make_mesh`` is the one spelling the launchers, tests and benchmarks use to
+build a device mesh: every axis is ``AxisType.Auto`` unless the caller says
+otherwise, so the SPMD partitioner shards what the specs leave open and the
+training step's ``shard_map`` regions choose their manual axes themselves.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5-era API: AxisType meshes and a fixed partial-auto partitioner
-    from jax.sharding import AxisType as _AxisType
-
-    HAS_AXIS_TYPES = True
-except ImportError:
-    _AxisType = None
-    HAS_AXIS_TYPES = False
-
-# Partial-auto shard_map regions (manual worker axes + auto model axis) only
-# partition reliably on jaxlibs new enough to ship AxisType; older SPMD
-# partitioners hit fatal CHECKs on any non-psum collective inside them
-# (spmd_partitioner.cc "IsManualSubgroup" — tests/test_known_limits.py).
-PARTIAL_AUTO_SHARD_MAP = HAS_AXIS_TYPES
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names, axis_types=None, devices=None):
-    """Version-portable ``jax.make_mesh``: Auto axis types when available."""
+    """``jax.make_mesh`` with Auto axis types by default."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(tuple(axis_shapes))
     kwargs = {} if devices is None else {"devices": devices}
-    if HAS_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = (_AxisType.Auto,) * len(tuple(axis_shapes))
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def _shard_map_shim(
-    f,
-    mesh=None,
-    in_specs=None,
-    out_specs=None,
-    axis_names=None,
-    check_vma=None,
-    check_rep=None,
-    auto=None,
-):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if mesh is None:
-        raise TypeError("shard_map shim requires an explicit mesh")
-    all_axes = frozenset(mesh.axis_names)
-    manual = frozenset(axis_names) if axis_names is not None else all_axes
-    if auto is None:
-        auto = all_axes - manual
-    check = check_vma if check_vma is not None else check_rep
-    if check is None:
-        check = True  # both native APIs default their check on
-    if auto and not PARTIAL_AUTO_SHARD_MAP:
-        # Degrade auto axes to manual replication (see module docstring):
-        # specs never mention them, so every tensor is replicated over them
-        # inside the region and the body's collectives stay legal. The
-        # static replication checker does not model the degrade, so it is
-        # forced off here — the one intentional False.
-        auto = frozenset()
-        check = False
-    return _shard_map(
-        f,
-        mesh,
-        in_specs,
-        out_specs,
-        check_rep=bool(check),
-        auto=frozenset(auto),
-    )
-
-
-def _axis_size_shim(axis_name):
-    """``lax.axis_size`` fallback: psum of a concrete 1 folds to a static int."""
-    if isinstance(axis_name, (tuple, list)):
-        size = 1
-        for a in axis_name:
-            size *= _axis_size_shim(a)
-        return size
-    return jax.lax.psum(1, axis_name)
-
-
-def install() -> None:
-    """Idempotently install the shims onto the jax namespace."""
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = _shard_map_shim
-    if not hasattr(jax.lax, "axis_size"):
-        jax.lax.axis_size = _axis_size_shim
-
-
-install()
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types, **kwargs)

@@ -16,11 +16,27 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _tile_rows(n_rows: int) -> int:
-    # Interpret mode executes the grid sequentially in the XLA interpreter,
-    # so one grid step over all rows is fastest on CPU; real TPU keeps the
-    # default 8-row tiles (VMEM-sized).
-    return n_rows if _use_interpret() else 8
+# f32 elements of one (tile, block) operand tile on TPU, counted with the
+# block padded to whole 128-lane vregs: 256 KiB keeps the double-buffered
+# in/out tiles plus the kernel's loop temporaries a few MiB, well inside the
+# default scoped VMEM
+_TILE_ELEMS = 64 * 1024
+
+
+def _tile_rows(n_rows: int, block: int) -> int:
+    """Row tile for ``topk_ef_pallas``, which rounds it up to a multiple of 8
+    and zero-pads the rows to a whole number of tiles. Interpret mode runs
+    the grid sequentially in the XLA interpreter, so one tile over all rows
+    is fastest on CPU. On TPU the tile is the largest power-of-two multiple
+    of 8 that fits ``_TILE_ELEMS``: power-of-two tiles divide most leaf row
+    counts, so the pad (a copy of the operands) is rarely needed."""
+    if _use_interpret():
+        return n_rows
+    lanes = -(-block // 128) * 128
+    tile = 8
+    while 2 * tile * lanes <= _TILE_ELEMS:
+        tile *= 2
+    return min(tile, n_rows)
 
 
 def block_topk(x: jax.Array, k: int, block_size: int = 2048) -> SparsePayload:
@@ -56,7 +72,7 @@ def blocked_topk_ef(
     e2 = err_blocked.reshape(rows, bc).astype(jnp.float32)
     new_err, vals, idx = topk_ef_pallas(
         g2, e2, jnp.float32(1.0), kb,
-        tile_blocks=_tile_rows(rows), interpret=_use_interpret(),
+        tile_blocks=_tile_rows(rows, bc), interpret=_use_interpret(),
     )
     return (
         vals.reshape(lead + (kb,)),
@@ -84,7 +100,7 @@ def topk_ef(
     g2 = jnp.where(pos < d, g2, 0.0)
     e2 = jnp.where(pos < d, e2, 0.0)
     new_err, vals, idx = topk_ef_pallas(
-        g2, e2, lr, kb, tile_blocks=_tile_rows(nb), interpret=_use_interpret()
+        g2, e2, lr, kb, tile_blocks=_tile_rows(nb, block_size), interpret=_use_interpret()
     )
     flat_idx = idx + (jnp.arange(nb, dtype=jnp.int32) * block_size)[:, None]
     in_range = flat_idx < d

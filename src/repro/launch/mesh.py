@@ -40,12 +40,6 @@ def make_production_mesh(*, multi_pod: bool = False, pipeline_stages: int = 1):
     return compat.make_mesh(shape, axes, devices=devices[:n])
 
 
-def make_test_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
-    from repro import compat
-
-    return compat.make_mesh(shape, axes)
-
-
 def required_device_count(multi_pod: bool) -> int:
     return 512 if multi_pod else 256
 

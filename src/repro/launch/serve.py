@@ -4,7 +4,6 @@
       --batch 4 --requests 12 --mesh-shape 4,2
 """
 import argparse
-import os
 import sys
 import time
 
@@ -18,6 +17,8 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--mesh-shape", default="4,2")
+    ap.add_argument("--fake-devices", type=int, default=0,
+                    help="fake CPU host devices (default on CPU: the mesh size)")
     ap.add_argument("--dense", action="store_true",
                     help="dense per-slot KV cache (default: paged when the "
                          "arch has global-attention layers)")
@@ -31,25 +32,26 @@ def main(argv=None):
     ndev = 1
     for s in shape:
         ndev *= s
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={ndev} "
-        + os.environ.get("XLA_FLAGS", "")
-    )
+    from repro.launch import runtime
+
+    runtime.force_host_devices(ndev, args.fake_devices)
 
     import jax
     import numpy as np
 
+    from repro.compat import make_mesh
     from repro.configs import get_config
-    from repro.launch.mesh import make_test_mesh
     from repro.models import build
     from repro.serve import BatchedServer, Request, build_serve
 
+    device = runtime.device_line()
+    print(f"[serve] {device} compile cache {runtime.enable_compile_cache()}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = build(cfg)
     axes = ("pod", "data", "model")[-len(shape):]
-    mesh = make_test_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     serve = build_serve(model, mesh, fsdp="data", tp="model")
     params = jax.jit(model.init, out_shardings=serve.param_shardings)(
         jax.random.PRNGKey(0)
@@ -71,7 +73,7 @@ def main(argv=None):
     mode = "paged" if srv.paged else "dense"
     print(f"[serve] {len(done)} requests, {stats['ticks']} engine ticks "
           f"({mode} cache, {stats['cache_dtype']}), "
-          f"{stats['decode_tokens'] / dt:.1f} tok/s (CPU, {ndev} fake devices)")
+          f"{stats['decode_tokens'] / dt:.1f} tok/s ({device})")
     if srv.paged:
         print(f"[serve] block high-water {stats['block_high_water']}"
               f"/{stats['num_blocks']}: {stats['high_water_bytes']:.0f} B "

@@ -3,12 +3,11 @@
   PYTHONPATH=src python -m repro.launch.train --arch llama3_8b --reduced \
       --algo sasg --steps 200 --mesh-shape 2,4 --ckpt-dir /tmp/ckpt
 
-On the single-CPU container use --fake-devices N to build a small mesh; on a
-real cluster jax.distributed.initialize() picks up the pod topology and the
-production mesh from launch/mesh.py applies.
+On the CPU (JAX_PLATFORMS=cpu, or --fake-devices N) the mesh is built from
+that many fake host devices; on an accelerator it uses the real devices, so
+--mesh-shape must multiply to at most the device count.
 """
 import argparse
-import os
 import sys
 
 
@@ -49,7 +48,8 @@ def main(argv=None):
                          "e.g. --mesh-shape 2,1 --stages 2)")
     ap.add_argument("--microbatches", type=int, default=0,
                     help="GPipe microbatches per worker (0 -> stages)")
-    ap.add_argument("--fake-devices", type=int, default=0)
+    ap.add_argument("--fake-devices", type=int, default=0,
+                    help="fake CPU host devices (default on CPU: the mesh size)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--remat", default="none")
@@ -70,13 +70,13 @@ def main(argv=None):
     ndev = 1
     for s in shape:
         ndev *= s
-    if args.fake_devices or ndev > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={max(args.fake_devices, ndev)} "
-            + os.environ.get("XLA_FLAGS", "")
-        )
+    from repro.launch import runtime
+
+    runtime.force_host_devices(ndev, args.fake_devices)
 
     import jax
+
+    from repro.compat import make_mesh
 
     from repro.configs import get_config
     from repro.core import PRESETS
@@ -86,7 +86,6 @@ def main(argv=None):
         synthetic_classification,
     )
     from repro.dist.strategy import choose_strategy
-    from repro.launch.mesh import make_test_mesh
     from repro.models import build
     from repro.optim import constant
     from repro.train import (
@@ -100,6 +99,8 @@ def main(argv=None):
     )
     from repro.core.types import tree_bytes
 
+    print(f"[train] {runtime.device_line()} "
+          f"compile cache {runtime.enable_compile_cache()}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -109,7 +110,7 @@ def main(argv=None):
         axes = ("pod", "data", "stage", "model")[-len(shape):]
     else:
         axes = ("pod", "data", "model")[-len(shape):]
-    mesh = make_test_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     params_bytes = tree_bytes(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     strategy = choose_strategy(
         mesh, sasg_enabled=args.algo != "sgd", params_bytes=params_bytes,
@@ -203,7 +204,7 @@ def main(argv=None):
             sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
             wa = strategy.worker_axes[0] if strategy.worker_axes else "data"
             sizes[wa] = n
-            return make_test_mesh(tuple(sizes.values()), tuple(sizes.keys()))
+            return make_mesh(tuple(sizes.values()), tuple(sizes.keys()))
 
         membership = WorkerMembership(
             model, scfg, constant(args.lr), mesh_fn=resized_mesh,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm import bits as bits_lib
 from repro.comm.transport import (
     ActivationLayout as TransportActivationLayout,
@@ -152,22 +151,6 @@ def build_train_step(
     optimizer: Optional[GradientTransformation] = None,
     donate: bool = True,
 ) -> BuiltStep:
-    if (
-        strategy.uses_shard_map
-        and strategy.fsdp_axis is not None
-        and not compat.PARTIAL_AUTO_SHARD_MAP
-    ):
-        # Old JAX only: the compat full-manual degrade would silently
-        # un-shard the params instead of reproducing the partitioner CHECK,
-        # so refuse eagerly. On partial-auto-capable JAX the config reaches
-        # XLA directly and tests/test_known_limits.py keeps probing whether
-        # the CHECK is fixed (at which point hierarchical FSDP can return).
-        raise NotImplementedError(
-            f"FSDP over {strategy.fsdp_axis!r} inside the manual worker "
-            "region hits an XLA SPMD partitioner CHECK "
-            "(tests/test_known_limits.py); hierarchical SASG is TP-only — "
-            "use fsdp_axis=None"
-        )
     fold_lr = sasg_cfg.fold_lr and strategy.uses_shard_map
     M = strategy.num_workers
     waxes = strategy.worker_axes
@@ -258,6 +241,15 @@ def build_train_step(
         build_stage_combine(pdef, stage)
         if stage is not None and not payload_mode else None
     )
+
+    # Manual axes of the worker region: the worker axes, the stage axis when
+    # pipelining, and every axis of size 1. A size-1 axis partitions nothing,
+    # so taking it as manual changes no numbers; it matters on TPU, whose
+    # compiler cannot auto-partition a Pallas kernel (the fused top-k/EF
+    # compressor) inside a region that still has auto axes.
+    manual = set(waxes) | ({stage} if stage is not None else set()) | {
+        a for a, n in zip(mesh.axis_names, mesh.devices.shape) if n == 1
+    }
 
     if strategy.uses_shard_map:
         # inner_dp stays an AUTO axis: the in-pod gradient mean over it is the
@@ -395,7 +387,7 @@ def build_train_step(
 
         def worker_fn(params, batch, wstate, gstate, lr, key, fs=None):
             wstate = strip_worker_axis(wstate)
-            if strategy.inner_dp and compat.PARTIAL_AUTO_SHARD_MAP:
+            if strategy.inner_dp and strategy.inner_dp not in manual:
                 batch = jax.tree.map(
                     lambda x: jax.lax.with_sharding_constraint(
                         x, P(strategy.inner_dp, *([None] * (x.ndim - 1)))
@@ -414,23 +406,20 @@ def build_train_step(
             # pin the densified update to the parameter sharding over the
             # AUTO axes (otherwise XLA replicates the fp32 update tree —
             # 32 GB/device on llama3-8b; EXPERIMENTS.md §Perf iteration 1)
-            manual_set = set(waxes) | ({stage} if stage is not None else set())
-
             def _strip_manual(spec):
                 out = []
                 for entry in tuple(spec):
                     names = entry if isinstance(entry, tuple) else (entry,)
-                    if entry is not None and any(n in manual_set for n in names):
+                    if entry is not None and any(n in manual for n in names):
                         out.append(None)
                     else:
                         out.append(entry)
                 return P(*out)
 
-            if compat.PARTIAL_AUTO_SHARD_MAP:
-                update = jax.tree.map(
-                    lambda u, s: jax.lax.with_sharding_constraint(u, _strip_manual(s)),
-                    update, pspecs,
-                )
+            update = jax.tree.map(
+                lambda u, s: jax.lax.with_sharding_constraint(u, _strip_manual(s)),
+                update, pspecs,
+            )
             return update, add_worker_axis(new_wstate), add_worker_axis(info)
 
         def _params_region_specs(params):
@@ -497,7 +486,6 @@ def build_train_step(
                 _wstate_region_specs(state.wstate),
                 ExchangeInfo(*([P(wa)] * len(ExchangeInfo._fields))),
             )
-            manual = set(waxes) | ({stage} if stage is not None else set())
             sm = jax.shard_map(
                 worker_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 axis_names=manual, check_vma=False,
@@ -620,19 +608,9 @@ def build_train_step(
         return fn(state, batch, jnp.asarray(force_skip, bool))
 
     def init(key):
-        if compat.HAS_AXIS_TYPES:
-            # modern jaxlib: partitionable threefry makes sharded-output RNG
-            # value-stable, so the state can be born sharded (no replicated
-            # transient — required for models that only fit sharded)
-            return jax.jit(init_all, out_shardings=state_shardings)(key)
-        # Pinned 0.4.x jaxlib: jit(out_shardings=...) partitions the threefry
-        # computation and yields global values that differ from the eager
-        # init (observed as a stage-count factor on stage-sharded trunk
-        # leaves). Initialize unsharded, then lay out with device_put — pure
-        # data movement, value-exact — at the cost of one transiently
-        # replicated state. Fine on the CPU test meshes; ROADMAP tracks
-        # re-verifying the direct sharded init after a jaxlib upgrade.
-        return jax.device_put(jax.jit(init_all)(key), state_shardings)
+        # partitionable threefry makes sharded-output RNG value-stable, so
+        # the state is born sharded (no replicated transient)
+        return jax.jit(init_all, out_shardings=state_shardings)(key)
 
     return BuiltStep(
         step=step,

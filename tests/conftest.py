@@ -18,7 +18,7 @@ import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
 
-import repro.compat  # noqa: E402,F401  (installs jax.shard_map/axis_size shims on older JAX)
+import repro.compat  # noqa: E402  (make_mesh for the mesh fixtures below)
 
 import pytest  # noqa: E402
 
@@ -166,8 +166,6 @@ def flat_pipe_check():
 
 @pytest.fixture(scope="session")
 def mesh2d():
-    # compat.make_mesh guards the AxisType import: older JAX builds the mesh
-    # without axis_types, newer JAX gets Auto axes.
     return repro.compat.make_mesh((4, 2), ("data", "model"))
 
 

@@ -52,6 +52,30 @@ def test_topk_ef_kernel_sweep(nb, bs, kb, lr):
     np.testing.assert_allclose(dense + np.asarray(ne_k), corrected, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("rows,bs,kb,tile", [
+    (4, 256, 3, 8),      # fewer rows than one tile
+    (37, 256, 3, 8),     # 8 does not divide the rows: 3 zero rows padded
+    (21, 137, 2, 16),    # an odd block width, as mamba2's w_in blocks are
+    (13, 2048, 21, 8),   # the flat layout's block and kb
+])
+def test_topk_ef_kernel_tpu_tiling_matches_ref(rows, bs, kb, tile):
+    """The tiling the chip uses (8-row multiples, zero-padded rows whose
+    outputs are dropped) selects exactly what ref.py selects: same indices
+    in the same order, same values and residual, bit for bit. lr is 1, as
+    on the compressor path (the learning rate is folded into the gradient
+    before the kernel), so no multiply-add contraction can round apart."""
+    rng = np.random.default_rng(rows * bs + kb)
+    g = jnp.asarray(rng.normal(size=(rows, bs)).astype(np.float32))
+    e = jnp.asarray(rng.normal(size=(rows, bs)).astype(np.float32)) * 0.1
+    ne_k, v_k, i_k = topk_ef_pallas(g, e, jnp.float32(1.0), kb,
+                                    tile_blocks=tile, interpret=True)
+    ne_r, v_r, i_r = topk_ef_ref(g, e, jnp.float32(1.0), kb)
+    assert ne_k.shape == (rows, bs) and v_k.shape == i_k.shape == (rows, kb)
+    np.testing.assert_array_equal(np.asarray(i_k), np.asarray(i_r))
+    np.testing.assert_array_equal(np.asarray(v_k), np.asarray(v_r))
+    np.testing.assert_array_equal(np.asarray(ne_k), np.asarray(ne_r))
+
+
 def test_topk_ef_ops_payload_roundtrip():
     from repro.kernels.topk_ef.ops import topk_ef
 

@@ -16,7 +16,7 @@ import pytest
 _PREFIX = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-from repro import compat  # installs jax.shard_map/axis_size shims on older JAX
+from repro import compat  # make_mesh with Auto axis types
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 mesh = compat.make_mesh((2,2,2), ("pod","data","model"))

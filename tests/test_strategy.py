@@ -1,10 +1,7 @@
 """choose_strategy edge cases: 1-D meshes, SASG off, replication threshold."""
-import pytest
-
 from repro import compat
 from repro.dist.strategy import (
     REPLICA_OVERHEAD,
-    Strategy,
     choose_strategy,
     worker_replication_fits,
 )
@@ -71,30 +68,3 @@ def test_params_bytes_threshold_boundary(mesh3d):
         replica_budget_bytes=budget,
     )
     assert s_over.name == "plain"
-
-
-@pytest.mark.skipif(
-    compat.PARTIAL_AUTO_SHARD_MAP,
-    reason="new JAX: the limit is probed live by the test_known_limits "
-    "subprocess repro instead of an eager guard",
-)
-def test_hierarchical_fsdp_is_rejected_by_build(mesh3d):
-    """On older JAX the documented limit is enforced eagerly: the compat
-    full-manual degrade could not reproduce the partitioner CHECK and would
-    silently un-shard the params instead."""
-    from repro.configs import get_config
-    from repro.core import sasg_config
-    from repro.models import build
-    from repro.optim import constant
-    from repro.train import build_train_step
-
-    cfg = get_config("llama3_8b").reduced()
-    model = build(cfg)
-    strat = Strategy(
-        "hierarchical", ("pod",), ("pod", "data"), "data", "data", "model", 2
-    )
-    with pytest.raises(NotImplementedError, match="TP-only"):
-        build_train_step(
-            model, sasg_config(k_ratio=0.05, max_delay=5), mesh3d, strat,
-            constant(0.05),
-        )
