@@ -17,7 +17,7 @@ from dataclasses import dataclass
 
 import jax
 
-from .types import CommCounters, Tree, tree_size
+from .types import CommCounters
 
 
 @dataclass(frozen=True)
@@ -160,6 +160,3 @@ class LinkModel:
             return per * num_uploads
         return per
 
-
-def model_dimension(params: Tree) -> int:
-    return tree_size(params)

@@ -38,6 +38,8 @@ from typing import Callable, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 # submodule imports (not the repro.comm package __init__) so that importing
 # repro.comm first does not cycle through repro.core -> sasg -> repro.comm
 from repro.comm.collectives import pmean_tree, psum_scalar
@@ -229,109 +231,124 @@ def build_exchange(
         Under pipeline parallelism ``grad_fn`` returns per-stage gradient
         slices; ``transport.gather`` combines them into the full tree
         (identity otherwise)."""
-        loss, g_fresh = grad_fn(params, batch)
-        g_fresh = _reduce(transport.gather(g_fresh))
-        if reduce_axes:
-            loss = pmean_tree(loss, reduce_axes)
+        with obs.scope("step.grad"):
+            loss, g_fresh = grad_fn(params, batch)
+            g_fresh = _reduce(transport.gather(g_fresh))
+            if reduce_axes:
+                loss = pmean_tree(loss, reduce_axes)
 
         if sel.enabled:
-            stale_p = jax.tree.map(
-                lambda s, p: s.astype(p.dtype), wstate.stale_params, params
-            )
-            if sel.probe_fraction < 1.0:
-                # rule (6) on a probe sub-batch: both sides re-evaluated on
-                # the same probe data (the variance-cancelling pairing is
-                # preserved); costs 2*p extra grads instead of 1x.
-                def probe(x):
-                    n = max(1, int(round(sel.probe_fraction * x.shape[0])))
-                    return x[:n]
+            with obs.scope("step.rule_grads"):
+                stale_p = jax.tree.map(
+                    lambda s, p: s.astype(p.dtype), wstate.stale_params, params
+                )
+                if sel.probe_fraction < 1.0:
+                    # rule (6) on a probe sub-batch: both sides re-evaluated on
+                    # the same probe data (the variance-cancelling pairing is
+                    # preserved); costs 2*p extra grads instead of 1x.
+                    def probe(x):
+                        n = max(1, int(round(sel.probe_fraction * x.shape[0])))
+                        return x[:n]
 
-                pbatch = jax.tree.map(probe, batch)
-                g_rule_fresh = _reduce(transport.gather(grad_fn(params, pbatch)[1]))
-                g_stale = _reduce(transport.gather(grad_fn(stale_p, pbatch)[1]))
-            else:
-                g_rule_fresh = g_fresh
-                g_stale = _reduce(transport.gather(grad_fn(stale_p, batch)[1]))
-            # alpha_d defaults to alpha_scale/lr (paper grid); lr is traced, so
-            # compute rhs directly here.
-            if sel.alphas is not None:
-                a = jnp.asarray(sel.alphas, jnp.float32)
-            else:
-                a = sel.alpha_scale / jnp.maximum(lr, 1e-12)
-                a = jnp.broadcast_to(a, (sel.max_delay,)).astype(jnp.float32)
-            sstate = SelectionState(tau=wstate.tau, window=gstate.window)
-            # payload-gather path: trunk grads are stage-local slices, so the
-            # rule's ||.||^2 must psum the trunk part over the stage axis
-            # (transport.diff_sq_norm) for all stages to agree on send/skip
-            dsn = transport.diff_sq_norm if transport.stage is not None else None
-            send = should_send(
-                sel, g_rule_fresh, g_stale, sstate, a, num_workers, force_skip,
-                diff_sq_norm=dsn,
-            )
-            if dsn is not None:
-                lhs = dsn(g_rule_fresh, g_stale)
-            else:
-                lhs = tree_sq_norm(jax.tree.map(jnp.subtract, g_rule_fresh, g_stale))
-            rhs = jnp.sum(a * gstate.window) / float(num_workers) ** 2
+                    pbatch = jax.tree.map(probe, batch)
+                    g_rule_fresh = _reduce(transport.gather(grad_fn(params, pbatch)[1]))
+                    g_stale = _reduce(transport.gather(grad_fn(stale_p, pbatch)[1]))
+                else:
+                    g_rule_fresh = g_fresh
+                    g_stale = _reduce(transport.gather(grad_fn(stale_p, batch)[1]))
+            with obs.scope("step.exchange"), obs.scope("rule"):
+                # alpha_d defaults to alpha_scale/lr (paper grid); lr is traced,
+                # so compute rhs directly here.
+                if sel.alphas is not None:
+                    a = jnp.asarray(sel.alphas, jnp.float32)
+                else:
+                    a = sel.alpha_scale / jnp.maximum(lr, 1e-12)
+                    a = jnp.broadcast_to(a, (sel.max_delay,)).astype(jnp.float32)
+                sstate = SelectionState(tau=wstate.tau, window=gstate.window)
+                # payload-gather path: trunk grads are stage-local slices, so
+                # the rule's ||.||^2 must psum the trunk part over the stage
+                # axis (transport.diff_sq_norm) for all stages to agree on
+                # send/skip
+                dsn = transport.diff_sq_norm if transport.stage is not None else None
+                send = should_send(
+                    sel, g_rule_fresh, g_stale, sstate, a, num_workers, force_skip,
+                    diff_sq_norm=dsn,
+                )
+                if dsn is not None:
+                    lhs = dsn(g_rule_fresh, g_stale)
+                else:
+                    lhs = tree_sq_norm(jax.tree.map(jnp.subtract, g_rule_fresh, g_stale))
+                rhs = jnp.sum(a * gstate.window) / float(num_workers) ** 2
         else:
             send = jnp.ones((), bool)
             lhs = jnp.zeros(())
             rhs = jnp.zeros(())
 
-        # Always upload on the very first step (empty caches).
-        send = send | (gstate.step == 0)
+        with obs.scope("step.exchange"):
+            with obs.scope("rule"):
+                # Always upload on the very first step (empty caches).
+                send = send | (gstate.step == 0)
 
-        # Paper eq. (8): g_m^t = gamma * grad + e_m^t (error folded inside the
-        # compressor; gamma folded here when fold_lr). The transport owns the
-        # wire layout, the worker-axis collectives, and densification — the
-        # densify template is the FULL gradient tree ``g``, never the params
-        # tree (whose trunk is stage-sliced under pipelining).
-        g = tree_scale(g_fresh, lr) if cfg.fold_lr else g_fresh
-        payload_fresh, comp_state_cand = transport.encode(wstate.comp_state, g, key)
-        # payload-gather path: the k-sized trunk payload slices all-gather
-        # over the stage axis HERE (identity otherwise) — the stale cache
-        # then stores the full gathered payload, so skip-step replays are
-        # collective-free over stages just like in the flat run
-        payload_fresh = transport.gather_payload(payload_fresh)
+            # Paper eq. (8): g_m^t = gamma * grad + e_m^t (error folded inside
+            # the compressor; gamma folded here when fold_lr). The transport
+            # owns the wire layout, the worker-axis collectives, and
+            # densification — the densify template is the FULL gradient tree
+            # ``g``, never the params tree (whose trunk is stage-sliced under
+            # pipelining).
+            with obs.scope("encode"):
+                g = tree_scale(g_fresh, lr) if cfg.fold_lr else g_fresh
+                payload_fresh, comp_state_cand = transport.encode(wstate.comp_state, g, key)
+                # payload-gather path: the k-sized trunk payload slices
+                # all-gather over the stage axis HERE (identity otherwise) —
+                # the stale cache then stores the full gathered payload, so
+                # skip-step replays are collective-free over stages just like
+                # in the flat run
+                payload_fresh = transport.gather_payload(payload_fresh)
 
-        if cfg.overlap:
-            # per-bucket select -> dispatch as each gradient bucket is ready,
-            # EF commit emitted AFTER the collectives (double-buffered
-            # candidate/old state pair) — bit-identical per-leaf ops to the
-            # sync path below. The traced ``send`` is passed even when the
-            # rule is off (it is then the constant-True first-step mask) so
-            # both paths emit the SAME where-gates: dropping them would
-            # change the program around the step's psums and XLA's
-            # all-reduce regrouping can shift their summation order by an
-            # ulp (send=None remains a transport-level API for callers whose
-            # sync path has no gates at all).
-            update, payload, comp_state_new = transport.exchange_overlapped(
-                payload_fresh, wstate.stale_cache, comp_state_cand,
-                wstate.comp_state, send, g,
-            )
-        else:
-            payload = tree_where(send, payload_fresh, wstate.stale_cache)
-            comp_state_new = tree_where(send, comp_state_cand, wstate.comp_state)
-            update = transport.densify(transport.exchange(payload), g)
+            if cfg.overlap:
+                # per-bucket select -> dispatch as each gradient bucket is
+                # ready, EF commit emitted AFTER the collectives
+                # (double-buffered candidate/old state pair) — bit-identical
+                # per-leaf ops to the sync path below. The traced ``send`` is
+                # passed even when the rule is off (it is then the
+                # constant-True first-step mask) so both paths emit the SAME
+                # where-gates: dropping them would change the program around
+                # the step's psums and XLA's all-reduce regrouping can shift
+                # their summation order by an ulp (send=None remains a
+                # transport-level API for callers whose sync path has no
+                # gates at all).
+                with obs.scope("collective"):
+                    update, payload, comp_state_new = transport.exchange_overlapped(
+                        payload_fresh, wstate.stale_cache, comp_state_cand,
+                        wstate.comp_state, send, g,
+                    )
+            else:
+                with obs.scope("commit"):
+                    payload = tree_where(send, payload_fresh, wstate.stale_cache)
+                    comp_state_new = tree_where(send, comp_state_cand, wstate.comp_state)
+                with obs.scope("collective"):
+                    update = transport.densify(transport.exchange(payload), g)
 
-        if sel.enabled:
-            stale_params_new = tree_where(
-                send,
-                tree_cast(params, jnp.dtype(cfg.stale_params_dtype)),
-                wstate.stale_params,
-            )
-        else:
-            stale_params_new = ()
+            with obs.scope("commit"):
+                if sel.enabled:
+                    stale_params_new = tree_where(
+                        send,
+                        tree_cast(params, jnp.dtype(cfg.stale_params_dtype)),
+                        wstate.stale_params,
+                    )
+                else:
+                    stale_params_new = ()
 
-        new_wstate = WorkerState(
-            comp_state=comp_state_new,
-            stale_cache=payload,
-            stale_params=stale_params_new,
-            tau=advance_tau(SelectionState(wstate.tau, gstate.window), send),
-        )
-        # send is identical within a reduce group (g_fresh was pmean'd over
-        # reduce_axes), so summing over worker axes alone counts |M^t|.
-        num_sent = psum_scalar(send.astype(jnp.float32), worker_axes)
+                new_wstate = WorkerState(
+                    comp_state=comp_state_new,
+                    stale_cache=payload,
+                    stale_params=stale_params_new,
+                    tau=advance_tau(SelectionState(wstate.tau, gstate.window), send),
+                )
+                # send is identical within a reduce group (g_fresh was pmean'd
+                # over reduce_axes), so summing over worker axes alone counts
+                # |M^t|.
+                num_sent = psum_scalar(send.astype(jnp.float32), worker_axes)
         info = ExchangeInfo(
             loss=loss, send=send, num_sent=num_sent, rule_lhs=lhs, rule_rhs=rhs
         )

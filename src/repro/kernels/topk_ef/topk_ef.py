@@ -100,6 +100,7 @@ def topk_ef_pallas(
             jax.ShapeDtypeStruct((padded, kb), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_ef",
     )(lr.reshape(1, 1).astype(jnp.float32), grad2d, err2d)
     if padded != nb:
         newerr, vals, idx = newerr[:nb], vals[:nb], idx[:nb]

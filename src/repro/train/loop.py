@@ -29,6 +29,8 @@ from typing import Callable, Iterator, Optional
 
 import jax
 
+from repro import obs
+
 from . import checkpoint as CKPT
 from .step import BuiltStep, TrainState
 
@@ -66,6 +68,7 @@ class Trainer:
         self.history: list[dict] = []
         self.events: list[dict] = []      # resizes, recoveries, lost ckpts
         self.batch_log: list[tuple] = []  # (step, fingerprint) when recording
+        self.spans = obs.Spans()          # host spans of the loop (repro.obs)
 
     # -- checkpointing -----------------------------------------------------
 
@@ -195,64 +198,81 @@ class Trainer:
     # -- main loop ----------------------------------------------------------
 
     def run(self, init_key=None, state: Optional[TrainState] = None) -> TrainState:
+        with self.spans.gc_spans():
+            return self._run(init_key, state)
+
+    def _run(self, init_key, state: Optional[TrainState]) -> TrainState:
         c = self.cfg
-        self._init_key = init_key if init_key is not None else jax.random.PRNGKey(0)
-        if state is None:
-            state = self.built.init(self._init_key)
-        state, start = self._restore_latest(state)
-        self._seek(start, initial=True)
+        with self.spans.span("train.start"):
+            self._init_key = init_key if init_key is not None else jax.random.PRNGKey(0)
+            if state is None:
+                state = self.built.init(self._init_key)
+            state, start = self._restore_latest(state)
+            self._seek(start, initial=True)
 
         step = start
         restarts = 0
         while step < c.total_steps:
             try:
-                state = self._pre_step(state, step)
-                batch = self._fetch_batch(step)
-                fs = self._force_skip(step)
-                if fs is None:
-                    state, mets = self.built.jit_step(state, batch)
-                else:
-                    state, mets = self.built.jit_step(state, batch, fs)
-                if step % c.log_every == 0 or step == c.total_steps - 1:
-                    loss = float(mets["loss"])
-                    sent = float(mets["num_sent"])
-                    self.log(
-                        f"[trainer] step {step:5d} loss {loss:8.4f} "
-                        f"sent {sent:4.0f}/{max(self.built.strategy.num_workers,1)} "
-                        f"rounds {float(mets['rounds_total']):9.0f} "
-                        f"bits(paper) {float(mets['bits_paper_total']):.3e}"
-                    )
-                self.history.append({k: float(v) for k, v in mets.items()})
-                if c.record_batches:
-                    from repro.data.replay import batch_fingerprint
+                with self.spans.span("train.step", step_num=step):
+                    state = self._pre_step(state, step)
+                    with self.spans.span("train.fetch"):
+                        batch = self._fetch_batch(step)
+                    fs = self._force_skip(step)
+                    with self.spans.span("train.dispatch"):
+                        if fs is None:
+                            state, mets = self.built.jit_step(state, batch)
+                        else:
+                            state, mets = self.built.jit_step(state, batch, fs)
+                    with self.spans.span("train.metrics_sync"):
+                        self._record_metrics(step, mets)
+                    if c.record_batches:
+                        from repro.data.replay import batch_fingerprint
 
-                    self.batch_log.append((step, batch_fingerprint(batch)))
-                step += 1
-                self._maybe_ckpt(state, step)
+                        self.batch_log.append((step, batch_fingerprint(batch)))
+                    step += 1
+                    with self.spans.span("train.checkpoint"):
+                        self._maybe_ckpt(state, step)
             except KeyboardInterrupt:
                 raise
             except Exception as e:  # node failure / data failure: recover
                 restarts += 1
                 if restarts > c.max_restarts:
                     raise
-                t0 = time.monotonic()
-                self.log(
-                    f"[trainer] step {step} failed ({type(e).__name__}: {e}); "
-                    f"recovering ({restarts}/{c.max_restarts})"
-                )
-                self._join_save()  # commit (or mourn) the in-flight save first
-                state, new_step = self._recover()
-                self.events.append(
-                    {
-                        "kind": "recovery",
-                        "failed_step": step,
-                        "restored_step": new_step,
-                        "steps_lost": step - new_step,
-                        "error": type(e).__name__,
-                        "latency_s": time.monotonic() - t0,
-                    }
-                )
-                step = new_step
+                with self.spans.span("train.recover", step_num=step):
+                    t0 = time.monotonic()
+                    self.log(
+                        f"[trainer] step {step} failed ({type(e).__name__}: {e}); "
+                        f"recovering ({restarts}/{c.max_restarts})"
+                    )
+                    self._join_save()  # commit (or mourn) the in-flight save first
+                    state, new_step = self._recover()
+                    self.events.append(
+                        {
+                            "kind": "recovery",
+                            "failed_step": step,
+                            "restored_step": new_step,
+                            "steps_lost": step - new_step,
+                            "error": type(e).__name__,
+                            "latency_s": time.monotonic() - t0,
+                        }
+                    )
+                    step = new_step
         self._maybe_ckpt(state, step, force=True)
         self._join_save()
         return state
+
+    def _record_metrics(self, step: int, mets: dict) -> None:
+        """The step's metrics on the host (where the host waits for the
+        device), logged every ``log_every`` steps and at the last."""
+        c = self.cfg
+        if step % c.log_every == 0 or step == c.total_steps - 1:
+            loss = float(mets["loss"])
+            sent = float(mets["num_sent"])
+            self.log(
+                f"[trainer] step {step:5d} loss {loss:8.4f} "
+                f"sent {sent:4.0f}/{max(self.built.strategy.num_workers,1)} "
+                f"rounds {float(mets['rounds_total']):9.0f} "
+                f"bits(paper) {float(mets['bits_paper_total']):.3e}"
+            )
+        self.history.append({k: float(v) for k, v in mets.items()})

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.comm import bits as bits_lib
 from repro.comm.transport import (
     ActivationLayout as TransportActivationLayout,
@@ -495,22 +496,26 @@ def build_train_step(
                 args = args + (jnp.asarray(force_skip, bool),)
             update, wstate, info = sm(*args)
 
-            if fold_lr:
-                delta, opt_state = update, state.opt_state
-            else:
-                delta, opt_state = optimizer.update(update, state.opt_state, state.params)
-            new_params = apply_updates(state.params, delta)
-            gstate = update_global_state(state.gstate, tree_sq_norm(delta))
-            num_sent = info.num_sent[0]
-            counters = CM.accumulate(state.counters, num_sent, bits_paper, bits_wire)
-            mets = {
-                "loss": jnp.mean(info.loss),
-                "num_sent": num_sent,
-                "lr": lr,
-                "rounds_total": counters.rounds,
-                "bits_paper_total": counters.bits_paper,
-                "bits_wire_total": counters.bits_wire,
-            }
+            with obs.scope("step.apply"):
+                if fold_lr:
+                    delta, opt_state = update, state.opt_state
+                else:
+                    delta, opt_state = optimizer.update(update, state.opt_state, state.params)
+                new_params = apply_updates(state.params, delta)
+                gstate = update_global_state(state.gstate, tree_sq_norm(delta))
+                num_sent = info.num_sent[0]
+                counters = CM.accumulate(state.counters, num_sent, bits_paper, bits_wire)
+                mets = {
+                    "loss": jnp.mean(info.loss),
+                    "num_sent": num_sent,
+                    "lr": lr,
+                    "rounds_total": counters.rounds,
+                    "bits_paper_total": counters.bits_paper,
+                    "bits_wire_total": counters.bits_wire,
+                    # the selection rule's two sides, worker mean (0 when off)
+                    "rule_lhs": jnp.mean(info.rule_lhs),
+                    "rule_rhs": jnp.mean(info.rule_rhs),
+                }
             if stage is not None:
                 # static per-stage ring traffic (CM.PipelineCommModel), every
                 # step, independent of the send/skip decisions. Engine-aware:
@@ -560,22 +565,24 @@ def build_train_step(
             # (every worker contributes to the dense psum) and is ignored
             count = state.counters.rounds.astype(jnp.int32)
             lr = lr_schedule(count)
-            loss, grads = vag(state.params, batch)
-            if optimizer is not None:
-                delta, opt_state = optimizer.update(grads, state.opt_state, state.params)
-            else:
-                delta = jax.tree.map(lambda g: lr * g.astype(jnp.float32), grads)
-                opt_state = state.opt_state
-            new_params = apply_updates(state.params, delta)
-            counters = CM.accumulate(state.counters, jnp.float32(1.0), bits_paper, bits_wire)
-            mets = {
-                "loss": loss,
-                "num_sent": jnp.float32(1.0),
-                "lr": lr,
-                "rounds_total": counters.rounds,
-                "bits_paper_total": counters.bits_paper,
-                "bits_wire_total": counters.bits_wire,
-            }
+            with obs.scope("step.grad"):
+                loss, grads = vag(state.params, batch)
+            with obs.scope("step.apply"):
+                if optimizer is not None:
+                    delta, opt_state = optimizer.update(grads, state.opt_state, state.params)
+                else:
+                    delta = jax.tree.map(lambda g: lr * g.astype(jnp.float32), grads)
+                    opt_state = state.opt_state
+                new_params = apply_updates(state.params, delta)
+                counters = CM.accumulate(state.counters, jnp.float32(1.0), bits_paper, bits_wire)
+                mets = {
+                    "loss": loss,
+                    "num_sent": jnp.float32(1.0),
+                    "lr": lr,
+                    "rounds_total": counters.rounds,
+                    "bits_paper_total": counters.bits_paper,
+                    "bits_wire_total": counters.bits_wire,
+                }
             return (
                 TrainState(new_params, opt_state, (), (), counters, state.rng),
                 mets,
