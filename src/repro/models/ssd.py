@@ -6,8 +6,9 @@ contraction; across chunks the SSM state h in R^{H x P x N} is carried by a
 linear recurrence (implemented with lax.scan — the cross-chunk loop is short:
 S/Q steps). Decode is the O(1) recurrent update.
 
-A Pallas kernel for the intra-chunk contraction lives in
-``repro.kernels.ssd_scan`` with this file's `ssd_chunked` as its oracle.
+On a TPU the chunked form runs as the Pallas kernels of
+``repro.kernels.ssd_scan`` (forward and a custom VJP), with this file's
+`ssd_chunked` as their oracle; elsewhere it runs as `ssd_chunked`.
 """
 from __future__ import annotations
 
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.ssd_scan import ops as ssd_ops
 
 
 def _pdtype(cfg):
@@ -161,12 +163,25 @@ def ssd_step(
     return y[:, None], hnew
 
 
+def _kernel_path(seq: int, chunk: int) -> bool:
+    """Whether the chunked scan runs as the Pallas kernels: on a TPU, over
+    whole chunks, and where no automatic partitioner would have to split
+    them (Mosaic kernels cannot be auto-partitioned) — on one device, or
+    inside a region in which every mesh axis larger than one is manual."""
+    if jax.default_backend() != "tpu" or seq <= 1 or seq % chunk:
+        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return jax.device_count() == 1
+    return all(n == 1 or t == jax.sharding.AxisType.Manual
+               for n, t in zip(mesh.axis_sizes, mesh.axis_types))
+
+
 def ssd_block_apply(
     params: Any,
     cfg: ModelConfig,
     xin: jax.Array,                 # (B, S, d)
     state: Optional[dict] = None,   # decode: {"h": (B,H,P,N), "conv": (B,K-1,C)}
-    use_kernel: bool = False,
 ):
     s = cfg.ssm
     dt_ = _dtype(cfg)
@@ -202,14 +217,8 @@ def ssd_block_apply(
         y, hfin = ssd_step(xh, dt, params["a_log"], bh, ch, state["h"])
     else:
         h0 = None if state is None else state["h"]
-        if use_kernel:
-            from repro.kernels.ssd_scan import ops as ssd_ops
-
-            y, hfin = ssd_ops.ssd_chunked(
-                xh, dt, params["a_log"], bh, ch, s.chunk_size, h0
-            )
-        else:
-            y, hfin = ssd_chunked(xh, dt, params["a_log"], bh, ch, s.chunk_size, h0)
+        chunked = ssd_ops.ssd_chunked if _kernel_path(seq, s.chunk_size) else ssd_chunked
+        y, hfin = chunked(xh, dt, params["a_log"], bh, ch, s.chunk_size, h0)
 
     y = y + params["d_skip"][None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(bsz, seq, d_inner)

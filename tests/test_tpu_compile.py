@@ -1,5 +1,6 @@
-"""Ahead-of-time compiles of the fused top-k/error-feedback kernel for a
-described TPU v5e, at the real widths of ``mamba2_370m``'s SASG step.
+"""Ahead-of-time compiles of the main path's Pallas kernels for a described
+TPU v5e, at the real widths of ``mamba2_370m``'s SASG step: the fused
+top-k/error-feedback kernel and the SSD's chunked-scan kernels.
 
 Nothing runs: the TPU compiler that ships with jaxlib lowers and compiles
 for a chip that is described, not attached, and refuses what the chip would
@@ -12,6 +13,7 @@ every pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ from repro.configs import get_config
 from repro.core import topk as topk_lib
 from repro.core.compressors import CompressorConfig, _blocked_kb
 from repro.core.types import tree_flatten_with_paths
+from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.topk_ef import ops
 
 
@@ -93,3 +96,40 @@ def test_flat_topk_ef_compiles_at_block_2048(one_chip, tpu_tiling):
     _assert_kernel_compiled(
         lambda g, e, lr: ops.topk_ef(g, e, lr, k, block_size=2048), x, x, lr
     )
+
+
+@pytest.fixture
+def ssd_compiled(monkeypatch):
+    monkeypatch.setattr(ssd_ops, "_use_interpret", lambda: False)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_ssd_kernels_compile_at_mamba2_widths(one_chip, ssd_compiled, direction):
+    """The chunked scan's forward (under jit) and its custom VJP's backward
+    (under grad) at the benchmark cell's shapes: 12 x 2048 tokens, 32 heads
+    of 64, one group of d_state 128, chunks of 256, bf16 activations."""
+    from repro.models.ssd import ssd_dims
+
+    cfg = get_config("mamba2_370m")
+    s = cfg.ssm
+    _, h = ssd_dims(cfg)
+    assert (h, s.head_dim, s.n_groups, s.d_state, s.chunk_size) == (32, 64, 1, 128, 256)
+    bsz, seq = 12, 2048
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg((bsz, seq, h, s.head_dim), jnp.bfloat16), arg((bsz, seq, h), jnp.float32),
+            arg((h,), jnp.float32), arg((bsz, seq, s.n_groups, s.d_state), jnp.bfloat16),
+            arg((bsz, seq, s.n_groups, s.d_state), jnp.bfloat16))
+
+    def fwd(x, dt, a_log, b, c):
+        return ssd_ops.ssd_chunked(x, dt, a_log, b, c, s.chunk_size)
+
+    def loss(*a):
+        y, hfin = fwd(*a)
+        return jnp.sum(y) + jnp.sum(hfin)
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert re.search(rf"%\S*ssd_chunk_{direction}\S* = .*tpu_custom_call", text)
